@@ -8,6 +8,6 @@ import sys
 
 from repro.launch.serve import main
 
-sys.argv = [sys.argv[0], "--arch", "gemma-7b", "--requests", "6",
-            "--max-new", "8", "--max-batch", "3", "--max-seq", "96"]
+sys.argv = [sys.argv[0], "--arch", "gemma-7b", "--reduce", "--requests",
+            "6", "--max-new", "8", "--max-batch", "3", "--max-seq", "96"]
 main()

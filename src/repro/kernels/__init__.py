@@ -2,8 +2,10 @@
 
 Each kernel package has kernel.py (pl.pallas_call + BlockSpec VMEM
 tiling), ops.py (jit wrapper) and ref.py (pure-jnp oracle).  Kernels are
-validated in interpret mode on CPU (tests/) and activate on real TPU via
-the ``use_pallas`` flag in the serve/train configs.
+validated against their oracles in interpret mode on CPU
+(tests/test_kernels.py), compiled for a described TPU v5e
+(tests/test_tpu_compile.py), and run on the chip by chip_smoke.py.  The
+model path does not call them yet.
 """
 
 from .decode_attention import decode_attention

@@ -27,7 +27,8 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     # flatten (B,S,H,P) → (B·H, S, P); broadcast groups to heads
     xf = x.transpose(0, 2, 1, 3).reshape(b * h, s, p)
     dtf = dt.transpose(0, 2, 1).reshape(b * h, s, 1)
-    af = jnp.broadcast_to(A[None], (b, h)).reshape(b * h, 1)
+    # one 32-bit scalar per (batch, head) row, prefetched into SMEM
+    af = jnp.broadcast_to(A[None], (b, h)).reshape(b * h).astype(jnp.float32)
     Bh = jnp.repeat(B, rep, axis=2)
     Ch = jnp.repeat(C, rep, axis=2)
     bf = Bh.transpose(0, 2, 1, 3).reshape(b * h, s, n)
@@ -35,7 +36,7 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
 
     call = build_ssd_call(bh=b * h, seq=s, p=p, n=n, chunk=chunk,
                           dtype=x.dtype, interpret=interpret)
-    yf, state = call(xf, dtf, af, bf, cf)
+    yf, state = call(af, xf, dtf, bf, cf)
     y = yf.reshape(b, h, s, p).transpose(0, 2, 1, 3)
     # kernel state layout (N, P) → model layout (P, N)
     final = state.reshape(b, h, n, p).transpose(0, 1, 3, 2)

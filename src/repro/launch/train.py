@@ -24,6 +24,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch
 from repro.configs import reduce_for_smoke
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.train import AdamWConfig
 from repro.train import StepTimer
@@ -49,6 +50,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduce:
         cfg = reduce_for_smoke(cfg)

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.sharding import act_axes
 from repro.sharding import constrain
 from repro.sharding import current_mesh
@@ -52,7 +51,7 @@ def row_parallel_out(y: jnp.ndarray, w: jnp.ndarray) -> Optional[jnp.ndarray]:
         return jax.lax.psum_scatter(part, "model", scatter_dimension=1,
                                     tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(dp, None, "model"), P("model", None)),
         out_specs=P(dp, "model", None), check_vma=False)(y, w)

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.sharding import current_mesh
 from repro.sharding import logical_spec
 
@@ -109,7 +108,7 @@ def moe_ffn(params, x, cfg, spec):
             "sh_down": P("model", None),
         }
         in_specs = (dp_spec, {k: pspecs[k] for k in body})
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(_moe_shard, spec=spec, act=act, axis="model"),
             mesh=mesh, in_specs=in_specs, out_specs=dp_spec,
             check_vma=False)

@@ -1,10 +1,10 @@
 """Tests for the logical-axis sharding rules."""
 
 import jax
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 import pytest
 
-from repro.compat import abstract_mesh
 from repro.sharding import act_axes
 from repro.sharding import constrain
 from repro.sharding import logical_spec
@@ -15,7 +15,7 @@ from repro.sharding.api import ACT_SEQ
 @pytest.fixture
 def mesh():
     # AbstractMesh: real axis sizes without needing 256 devices
-    return abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_no_mesh_is_noop():
@@ -61,7 +61,7 @@ def test_act_axes_flag():
 
 
 def test_multipod_spec():
-    mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     spec = logical_spec(("dp", None, "tp"), mesh)
     assert spec == P(("pod", "data"), None, "model")
 

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager
-from repro.compat import shard_map
 from repro.configs import get_arch
 from repro.configs import reduce_for_smoke
 from repro.data import SyntheticLM
@@ -115,7 +114,7 @@ def test_compressed_psum_single_shard_roundtrip():
         return compressed_psum_mean(g, e, "pod")
 
     from jax.sharding import PartitionSpec as P
-    out, err = jax.jit(shard_map(
+    out, err = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False))(g, e)
     q_err = np.abs(np.asarray(out["w"]) - np.asarray(g["w"]))
@@ -131,7 +130,7 @@ def test_compressed_psum_error_feedback_converges():
     g = {"w": jnp.asarray([[0.003, -0.7], [0.31, 0.02]])}
     e = init_error_feedback(g)
     from jax.sharding import PartitionSpec as P
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         lambda g, e: compressed_psum_mean(g, e, "pod"), mesh=mesh,
         in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False))
     total = jnp.zeros_like(g["w"])
