@@ -125,7 +125,7 @@ def check_decode_logits(engine: ServeEngine, prompt: np.ndarray,
     plen = len(prompt)
     logits, one = engine._prefill(engine.params, jnp.asarray(prompt[None]))
     tok = int(jnp.argmax(logits[0]))
-    cache = _splice(engine.cache, one, slot, plen, engine.max_seq)
+    cache = _splice(engine.cache, one, slot)
     toks = np.zeros((engine.max_batch, 1), np.int32)
     toks[slot, 0] = tok
     dec, _ = engine._decode(engine.params, jnp.asarray(toks),

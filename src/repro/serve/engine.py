@@ -11,6 +11,12 @@ slot lifetimes so the analogy is executable, not rhetorical.
 The engine is deliberately synchronous and functional: ``step()`` runs one
 batched decode for every active slot (padding inactive slots), so the
 whole loop jit-compiles to a single ``decode_step`` of static shape.
+
+Its device programs have stable names, so that a profiler trace finds
+them: ``serve_prefill``, ``serve_decode``, ``serve_merge_slots`` and
+``serve_splice``.  With a :class:`~repro.serve.tracing.Tracer` the engine
+records spans (all named ``serve.*``) and counters of its work; without
+one it records nothing.
 """
 
 from __future__ import annotations
@@ -35,6 +41,9 @@ from repro.models import prefill
 
 from .scheduler import ServeTruncation
 from .scheduler import SlotScheduler
+from .tracing import NO_SPAN
+from .tracing import Span
+from .tracing import Tracer
 
 
 @dataclass
@@ -48,8 +57,21 @@ class Request:
 
 
 class ServeEngine:
+    """Continuous batching over ``max_batch`` cache slots.
+
+    With ``tracer`` each :meth:`step` records a ``serve.step`` span holding
+    ``serve.admit`` (``prompt_len``; children ``serve.prefill``,
+    ``serve.splice``, ``serve.first_token``) for each request admitted, and
+    ``serve.decode``, ``serve.merge`` and ``serve.sample`` for each group
+    of slots at one position; each request records ``serve.queue`` from
+    :meth:`add_request` to its admission.  The step's counters are
+    attributes of its span: ``groups`` (decode groups) and ``host_reads``
+    (device values read by the host).
+    """
+
     def __init__(self, cfg: ArchConfig, params, *, max_batch: int = 4,
-                 max_seq: int = 256, greedy: bool = True):
+                 max_seq: int = 256, greedy: bool = True,
+                 tracer: Optional[Tracer] = None):
         self.cfg = cfg
         self.params = params
         self.max_batch = max_batch
@@ -61,14 +83,25 @@ class ServeEngine:
         # TMU tracking slot lifetimes (dead-block analogue)
         self._tmu = TMU(tensor_entries=max_batch * 2)
         self._slot_bytes = 1 << 20
+        self.tracer = tracer
+        self._host_reads = 0              # device values read by the host
+        self._waiting: Dict[int, Span] = {}       # id(request) -> its wait
 
-        self._decode = jax.jit(
-            lambda p, t, c: decode_step(p, t, c, cfg))
-        self._prefill = jax.jit(
-            lambda p, t: prefill(p, t, cfg))
+        def serve_decode(p, t, c):
+            return decode_step(p, t, c, cfg)
+
+        def serve_prefill(p, t):
+            return prefill(p, t, cfg)
+
+        # looked up on the instance at each call, so callers may wrap them
+        self._decode = jax.jit(serve_decode)
+        self._prefill = jax.jit(serve_prefill)
 
     # ------------------------------------------------------------------
     def add_request(self, req: Request) -> None:
+        if self.tracer:
+            self._waiting[id(req)] = self.tracer.start(
+                "serve.queue", req.uid, nested=False)
         self.sched.add(req)
 
     def _admit(self) -> None:
@@ -76,14 +109,25 @@ class ServeEngine:
             self._start(slot, req)
 
     def _start(self, slot: int, req: Request) -> None:
-        prompt = jnp.asarray(req.prompt[None, :])
-        logits, pcache = self._prefill(self.params, prompt)
+        t = self.tracer
         plen = req.prompt.shape[0]
-        # splice this request's prefilled KV/state into the pooled cache
-        self.cache = _splice(self.cache, pcache, slot, plen, self.max_seq)
-        self.slot_pos[slot] = plen
-        first = int(jnp.argmax(logits[0])) if self.greedy else int(
-            jax.random.categorical(jax.random.key(req.uid), logits[0]))
+        waited = self._waiting.pop(id(req), None)
+        if waited is not None:
+            t.finish(waited)
+        with t.span("serve.admit", req.uid, prompt_len=plen) if t \
+                else NO_SPAN:
+            with t.span("serve.prefill") if t else NO_SPAN:
+                prompt = jnp.asarray(req.prompt[None, :])
+                logits, pcache = self._prefill(self.params, prompt)
+            # splice this request's prefilled KV/state into the pooled cache
+            with t.span("serve.splice") if t else NO_SPAN:
+                self.cache = _splice(self.cache, pcache, slot)
+            self.slot_pos[slot] = plen
+            with t.span("serve.first_token") if t else NO_SPAN:
+                first = int(jnp.argmax(logits[0])) if self.greedy else int(
+                    jax.random.categorical(jax.random.key(req.uid),
+                                           logits[0]))
+                self._host_reads += 1
         req.tokens_out.append(first)
         self._tmu.register(TensorMeta(
             tensor_id=req.uid, base_addr=slot * self._slot_bytes,
@@ -99,10 +143,21 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def step(self) -> int:
         """One batched decode step; returns #active slots."""
-        self._admit()
-        active = self.sched.active_slots()
-        if not active:
-            return 0
+        t = self.tracer
+        reads_before = self._host_reads
+        with t.span("serve.step") if t else NO_SPAN as sp:
+            self._admit()
+            active = self.sched.active_slots()
+            groups = self._decode_groups(active) if active else {}
+            if t:
+                sp.attrs.update(groups=len(groups),
+                                host_reads=self._host_reads - reads_before)
+        return len(active)
+
+    def _decode_groups(self, active: List[int]) -> Dict[int, List[int]]:
+        """Decodes one token for every active slot; returns the slots of
+        each position group."""
+        t = self.tracer
         toks = np.zeros((self.max_batch, 1), dtype=np.int32)
         for i in active:
             toks[i, 0] = self.sched.slots[i].tokens_out[-1]
@@ -114,22 +169,26 @@ class ServeEngine:
         for i in active:
             groups.setdefault(int(self.slot_pos[i]), []).append(i)
         for pos, slots in groups.items():
-            cache = self.cache._replace(pos=jnp.asarray(pos, jnp.int32))
-            logits, new_cache = self._decode(
-                self.params, jnp.asarray(toks), cache)
-            self.cache = _merge_slots(self.cache, new_cache, slots)
-            for i in slots:
-                req = self.sched.slots[i]
-                nxt = int(jnp.argmax(logits[i, 0]))
-                req.tokens_out.append(nxt)
-                self.slot_pos[i] += 1
-                self._tmu.on_access(
-                    i * self._slot_bytes + self._slot_bytes - 128, 0)
-                exhausted = len(req.tokens_out) >= req.max_new_tokens
-                if exhausted or (req.eos_id is not None
-                                 and nxt == req.eos_id):
-                    self._retire(i)
-        return len(active)
+            with t.span("serve.decode") if t else NO_SPAN:
+                cache = self.cache._replace(pos=jnp.asarray(pos, jnp.int32))
+                logits, new_cache = self._decode(
+                    self.params, jnp.asarray(toks), cache)
+            with t.span("serve.merge") if t else NO_SPAN:
+                self.cache = _merge_slots(self.cache, new_cache, slots)
+            with t.span("serve.sample") if t else NO_SPAN:
+                for i in slots:
+                    req = self.sched.slots[i]
+                    nxt = int(jnp.argmax(logits[i, 0]))
+                    self._host_reads += 1
+                    req.tokens_out.append(nxt)
+                    self.slot_pos[i] += 1
+                    self._tmu.on_access(
+                        i * self._slot_bytes + self._slot_bytes - 128, 0)
+                    exhausted = len(req.tokens_out) >= req.max_new_tokens
+                    if exhausted or (req.eos_id is not None
+                                     and nxt == req.eos_id):
+                        self._retire(i)
+        return groups
 
     def run_to_completion(self, max_steps: int = 1000) -> int:
         """Drive :meth:`step` until every request finishes; returns the
@@ -147,13 +206,19 @@ class ServeEngine:
 
 
 # ---------------------------------------------------------------------------
-def _splice(pool: Cache, one: Cache, slot: int, plen: int,
-            max_seq: int) -> Cache:
+def _splice(pool: Cache, one: Cache, slot: int) -> Cache:
     """Copy a single-sequence prefill cache into pool slot ``slot``."""
+    return serve_splice(pool, one, np.int32(slot))
+
+
+@jax.jit
+def serve_splice(pool: Cache, one: Cache, slot) -> Cache:
+    """One program per prompt length: ``slot`` is traced, so any slot
+    runs the same program."""
     def put_kv(pool_a, one_a):
         if pool_a is None:
             return None
-        pad = max_seq - one_a.shape[2]
+        pad = pool_a.shape[2] - one_a.shape[2]
         padded = jnp.pad(one_a, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
         return jax.lax.dynamic_update_slice_in_dim(pool_a, padded, slot,
                                                    axis=1)
@@ -177,8 +242,12 @@ def _merge_slots(old: Cache, new: Cache, slots: List[int]) -> Cache:
     sel = np.zeros(old.k.shape[1] if old.k is not None
                    else old.ssm.shape[1], dtype=bool)
     sel[slots] = True
-    mask = jnp.asarray(sel)
+    return serve_merge_slots(old, new, sel)
 
+
+@jax.jit
+def serve_merge_slots(old: Cache, new: Cache, mask) -> Cache:
+    """One program for every set of slots: ``mask`` (batch,) is traced."""
     def pick(o, n, bdim=1):
         if o is None:
             return None
