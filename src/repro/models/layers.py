@@ -159,13 +159,23 @@ def gqa_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return out.reshape(b, sq, h, d).astype(q.dtype)
 
 
+def _write_rows(cache: jnp.ndarray, x: jnp.ndarray,
+                pos: jnp.ndarray) -> jnp.ndarray:
+    """``cache`` (B, S_max, G, D) with row ``i`` of ``x`` (B, s, G, D)
+    written at positions ``pos[i]`` .. ``pos[i] + s - 1``."""
+    b, s = x.shape[:2]
+    cols = pos[:, None] + jnp.arange(s)
+    return cache.at[jnp.arange(b)[:, None], cols].set(x.astype(cache.dtype))
+
+
 def attention_block(params, x, cfg, *, layer_is_local=None, positions=None,
                     kv_cache=None, cache_pos=None):
     """Full attention sub-block: norm → qkv → rope → attn → out-proj.
 
-    With ``kv_cache=(k, v)`` (B, S_max, G, D) and scalar ``cache_pos``,
-    runs in decode mode: writes the new K/V at ``cache_pos`` and attends
-    over the cache.  Returns (out, new_kv_cache_or_None).
+    With ``kv_cache=(k, v)`` (B, S_max, G, D), runs in decode mode: writes
+    the new K/V at ``cache_pos`` and attends over the cache.  A scalar
+    ``cache_pos`` writes every row at that position; a (B,) vector writes
+    each row at its own.  Returns (out, new_kv_cache_or_None).
     """
     b, s, _ = x.shape
     h = rms_norm(x, params["ln"], plus_one=cfg.gemma_norm)
@@ -178,7 +188,12 @@ def attention_block(params, x, cfg, *, layer_is_local=None, positions=None,
     v = constrain(v, ("dp", None, "tp", None))
 
     if positions is None:
-        base = jnp.arange(s) if cache_pos is None else cache_pos + jnp.arange(s)
+        if cache_pos is None:
+            base = jnp.arange(s)
+        elif jnp.ndim(cache_pos) == 0:
+            base = cache_pos + jnp.arange(s)
+        else:
+            base = cache_pos[:, None] + jnp.arange(s)
         positions = jnp.broadcast_to(base, (b, s))
     q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections)
@@ -202,8 +217,12 @@ def attention_block(params, x, cfg, *, layer_is_local=None, positions=None,
         new_cache = None
     else:
         ck, cv = kv_cache
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_pos, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_pos, axis=1)
+        if jnp.ndim(cache_pos) == 0:
+            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_pos, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_pos, axis=1)
+        else:
+            ck = _write_rows(ck, k, cache_pos)
+            cv = _write_rows(cv, v, cache_pos)
         s_max = ck.shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(s_max), (b, s_max))
         # mask out unwritten slots via position comparison (kv_pos > current)

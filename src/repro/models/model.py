@@ -307,7 +307,8 @@ class Cache(NamedTuple):
     conv_x: Optional[jnp.ndarray] = None     # (L, B, K-1, d_inner)
     conv_bc: Optional[jnp.ndarray] = None    # (L, B, K-1, 2GN)
     ssm: Optional[jnp.ndarray] = None        # (L, B, H, P, N)
-    pos: Optional[jnp.ndarray] = None        # scalar int32: next position
+    pos: Optional[jnp.ndarray] = None        # scalar or (B,) int32: next
+                                             # position of each row
 
 
 def _n_attn_apps(cfg: ArchConfig) -> int:
@@ -355,10 +356,14 @@ def cache_logical_axes(cfg: ArchConfig) -> Cache:
 def decode_step(params, tokens, cache: Cache, cfg: ArchConfig, *,
                 input_embeds: Optional[jnp.ndarray] = None
                 ) -> Tuple[jnp.ndarray, Cache]:
-    """tokens (B, 1) → (logits (B, 1, V), updated cache)."""
+    """tokens (B, 1) → (logits (B, 1, V), updated cache).
+
+    ``cache.pos`` is a scalar (every row at one position) or a (B,)
+    vector (each row at its own); the returned ``pos`` keeps its shape.
+    """
     b = tokens.shape[0]
     pos = cache.pos
-    positions = jnp.broadcast_to(pos, (b, 1))
+    positions = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)), (b, 1))
     x = embed_tokens(params, tokens, cfg, input_embeds)
 
     if cfg.family == DENSE:

@@ -10,7 +10,10 @@ slot lifetimes so the analogy is executable, not rhetorical.
 
 The engine is deliberately synchronous and functional: ``step()`` runs one
 batched decode for every active slot (padding inactive slots), so the
-whole loop jit-compiles to a single ``decode_step`` of static shape.
+whole loop jit-compiles to a single ``decode_step`` of static shape.  The
+pooled cache's ``pos`` is a (B,) vector, one position per slot, so slots
+at different positions share that one call and its returned cache is
+kept whole.
 
 Its device programs have stable names, so that a profiler trace finds
 them: ``serve_prefill``, ``serve_decode``, ``serve_merge_slots`` and
@@ -62,11 +65,11 @@ class ServeEngine:
     With ``tracer`` each :meth:`step` records a ``serve.step`` span holding
     ``serve.admit`` (``prompt_len``; children ``serve.prefill``,
     ``serve.splice``, ``serve.first_token``) for each request admitted, and
-    ``serve.decode``, ``serve.merge`` and ``serve.sample`` for each group
-    of slots at one position; each request records ``serve.queue`` from
-    :meth:`add_request` to its admission.  The step's counters are
-    attributes of its span: ``groups`` (decode groups) and ``host_reads``
-    (device values read by the host).
+    one ``serve.decode`` and one ``serve.sample`` if any slot is active;
+    each request records ``serve.queue`` from :meth:`add_request` to its
+    admission.  The step's counters are attributes of its span: ``groups``
+    (decode calls: 1 or 0) and ``host_reads`` (device values read by the
+    host).
     """
 
     def __init__(self, cfg: ArchConfig, params, *, max_batch: int = 4,
@@ -76,7 +79,8 @@ class ServeEngine:
         self.params = params
         self.max_batch = max_batch
         self.max_seq = max_seq
-        self.cache = init_cache(cfg, max_batch, max_seq)
+        self.cache = init_cache(cfg, max_batch, max_seq)._replace(
+            pos=jnp.zeros((max_batch,), jnp.int32))
         self.sched: SlotScheduler[Request] = SlotScheduler(max_batch)
         self.slot_pos = np.zeros(max_batch, dtype=np.int32)
         self.greedy = greedy
@@ -148,47 +152,40 @@ class ServeEngine:
         with t.span("serve.step") if t else NO_SPAN as sp:
             self._admit()
             active = self.sched.active_slots()
-            groups = self._decode_groups(active) if active else {}
+            if active:
+                self._decode_active(active)
             if t:
-                sp.attrs.update(groups=len(groups),
+                sp.attrs.update(groups=int(bool(active)),
                                 host_reads=self._host_reads - reads_before)
         return len(active)
 
-    def _decode_groups(self, active: List[int]) -> Dict[int, List[int]]:
-        """Decodes one token for every active slot; returns the slots of
-        each position group."""
+    def _decode_active(self, active: List[int]) -> None:
+        """Decodes one token for every active slot in one call, each slot
+        at its own position.  Inactive slots decode at position 0 too;
+        what that writes into their rows is never read, because an
+        admission splices the whole row."""
         t = self.tracer
         toks = np.zeros((self.max_batch, 1), dtype=np.int32)
         for i in active:
             toks[i, 0] = self.sched.slots[i].tokens_out[-1]
-        # batched decode at the max position (positions are per-slot via
-        # cache.pos; we use per-slot positions by patching pos before the
-        # call — a single scalar pos requires aligned decoding, so the
-        # engine decodes each distinct position group separately)
-        groups: Dict[int, List[int]] = {}
-        for i in active:
-            groups.setdefault(int(self.slot_pos[i]), []).append(i)
-        for pos, slots in groups.items():
-            with t.span("serve.decode") if t else NO_SPAN:
-                cache = self.cache._replace(pos=jnp.asarray(pos, jnp.int32))
-                logits, new_cache = self._decode(
-                    self.params, jnp.asarray(toks), cache)
-            with t.span("serve.merge") if t else NO_SPAN:
-                self.cache = _merge_slots(self.cache, new_cache, slots)
-            with t.span("serve.sample") if t else NO_SPAN:
-                for i in slots:
-                    req = self.sched.slots[i]
-                    nxt = int(jnp.argmax(logits[i, 0]))
-                    self._host_reads += 1
-                    req.tokens_out.append(nxt)
-                    self.slot_pos[i] += 1
-                    self._tmu.on_access(
-                        i * self._slot_bytes + self._slot_bytes - 128, 0)
-                    exhausted = len(req.tokens_out) >= req.max_new_tokens
-                    if exhausted or (req.eos_id is not None
-                                     and nxt == req.eos_id):
-                        self._retire(i)
-        return groups
+        with t.span("serve.decode") if t else NO_SPAN:
+            # a copy: slot_pos moves on while the call may still read it
+            cache = self.cache._replace(pos=jnp.array(self.slot_pos))
+            logits, self.cache = self._decode(
+                self.params, jnp.asarray(toks), cache)
+        with t.span("serve.sample") if t else NO_SPAN:
+            for i in active:
+                req = self.sched.slots[i]
+                nxt = int(jnp.argmax(logits[i, 0]))
+                self._host_reads += 1
+                req.tokens_out.append(nxt)
+                self.slot_pos[i] += 1
+                self._tmu.on_access(
+                    i * self._slot_bytes + self._slot_bytes - 128, 0)
+                exhausted = len(req.tokens_out) >= req.max_new_tokens
+                if exhausted or (req.eos_id is not None
+                                 and nxt == req.eos_id):
+                    self._retire(i)
 
     def run_to_completion(self, max_steps: int = 1000) -> int:
         """Drive :meth:`step` until every request finishes; returns the
@@ -238,7 +235,8 @@ def serve_splice(pool: Cache, one: Cache, slot) -> Cache:
 
 
 def _merge_slots(old: Cache, new: Cache, slots: List[int]) -> Cache:
-    """Keep updated cache rows only for ``slots`` (batch axis 1)."""
+    """Keep updated cache rows only for ``slots`` (batch axis 1).
+    :meth:`ServeEngine.step` does not merge; this is a utility."""
     sel = np.zeros(old.k.shape[1] if old.k is not None
                    else old.ssm.shape[1], dtype=bool)
     sel[slots] = True

@@ -73,8 +73,7 @@ def test_spans_nest_and_share_the_request_uid(model):
     want = {"serve.step": None, "serve.queue": None,
             "serve.admit": "serve.step", "serve.prefill": "serve.admit",
             "serve.splice": "serve.admit", "serve.first_token": "serve.admit",
-            "serve.decode": "serve.step", "serve.merge": "serve.step",
-            "serve.sample": "serve.step"}
+            "serve.decode": "serve.step", "serve.sample": "serve.step"}
     assert {(s.name, parent[s.id]) for s in spans} == set(want.items())
     for s in spans:
         assert s.start <= s.end
@@ -88,8 +87,7 @@ def test_spans_nest_and_share_the_request_uid(model):
                         "serve.prefill": 1, "serve.splice": 1,
                         "serve.first_token": 1}
     assert all(s.uid is None for s in spans
-               if s.name in ("serve.step", "serve.decode", "serve.merge",
-                             "serve.sample"))
+               if s.name in ("serve.step", "serve.decode", "serve.sample"))
     admit = [s for s in spans if s.name == "serve.admit"]
     assert sorted(s.attrs["prompt_len"] for s in admit) == sorted(LENS)
     # a queue span ends where its request's admission starts
