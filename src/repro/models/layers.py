@@ -159,23 +159,31 @@ def gqa_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return out.reshape(b, sq, h, d).astype(q.dtype)
 
 
-def _write_rows(cache: jnp.ndarray, x: jnp.ndarray,
-                pos: jnp.ndarray) -> jnp.ndarray:
-    """``cache`` (B, S_max, G, D) with row ``i`` of ``x`` (B, s, G, D)
-    written at positions ``pos[i]`` .. ``pos[i] + s - 1``."""
+def _write_rows(cache: jnp.ndarray, x: jnp.ndarray, pos: jnp.ndarray,
+                layer: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """``cache`` (B, S_max, G, D), or layer ``layer`` of the stacked pool
+    (L, B, S_max, G, D) in place, with row ``i`` of ``x`` (B, s, G, D)
+    written at positions ``pos[i]`` .. ``pos[i] + s - 1`` (every row from
+    ``pos`` when it is a scalar)."""
     b, s = x.shape[:2]
-    cols = pos[:, None] + jnp.arange(s)
-    return cache.at[jnp.arange(b)[:, None], cols].set(x.astype(cache.dtype))
+    cols = jnp.expand_dims(pos, -1) + jnp.arange(s)
+    idx = (jnp.arange(b)[:, None], cols)
+    if layer is not None:
+        idx = (layer,) + idx
+    return cache.at[idx].set(x.astype(cache.dtype))
 
 
 def attention_block(params, x, cfg, *, layer_is_local=None, positions=None,
-                    kv_cache=None, cache_pos=None):
+                    kv_cache=None, cache_pos=None, layer=None):
     """Full attention sub-block: norm → qkv → rope → attn → out-proj.
 
     With ``kv_cache=(k, v)`` (B, S_max, G, D), runs in decode mode: writes
     the new K/V at ``cache_pos`` and attends over the cache.  A scalar
     ``cache_pos`` writes every row at that position; a (B,) vector writes
-    each row at its own.  Returns (out, new_kv_cache_or_None).
+    each row at its own.  With ``layer``, ``kv_cache`` is the stacked
+    (L, B, S_max, G, D) pool: the new K/V are written in place into layer
+    ``layer``, attention reads that layer, and the pool is returned whole.
+    Returns (out, new_kv_cache_or_None).
     """
     b, s, _ = x.shape
     h = rms_norm(x, params["ln"], plus_one=cfg.gemma_norm)
@@ -217,16 +225,23 @@ def attention_block(params, x, cfg, *, layer_is_local=None, positions=None,
         new_cache = None
     else:
         ck, cv = kv_cache
-        if jnp.ndim(cache_pos) == 0:
+        if layer is not None:
+            ck = _write_rows(ck, k, cache_pos, layer)
+            cv = _write_rows(cv, v, cache_pos, layer)
+            k_l = jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
+            v_l = jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
+        elif jnp.ndim(cache_pos) == 0:
             ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_pos, axis=1)
             cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_pos, axis=1)
+            k_l, v_l = ck, cv
         else:
             ck = _write_rows(ck, k, cache_pos)
             cv = _write_rows(cv, v, cache_pos)
-        s_max = ck.shape[1]
+            k_l, v_l = ck, cv
+        s_max = k_l.shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(s_max), (b, s_max))
         # mask out unwritten slots via position comparison (kv_pos > current)
-        out = gqa_attention(q, ck.astype(q.dtype), cv.astype(q.dtype),
+        out = gqa_attention(q, k_l.astype(q.dtype), v_l.astype(q.dtype),
                             causal=True, window=window,
                             softcap=cfg.attn_softcap, scale=scale,
                             q_positions=positions, kv_positions=kv_pos)

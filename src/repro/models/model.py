@@ -372,19 +372,14 @@ def decode_step(params, tokens, cache: Cache, cfg: ArchConfig, *,
         new = Cache(k=nk, v=nv, pos=pos + 1)
     elif cfg.family == MOE:
         nd = cfg.moe.first_dense
-        ks, vs = [], []
+        nk, nv = cache.k, cache.v
         if nd:
             x, nk, nv = _dense_decode(params["dense_layers"], x, cfg,
-                                      positions, cache.k[:nd], cache.v[:nd],
-                                      pos, local_flags(cfg, nd))
-            ks.append(nk)
-            vs.append(nv)
+                                      positions, nk, nv, pos,
+                                      local_flags(cfg, nd))
         x, nk, nv = _moe_decode(params["moe_layers"], x, cfg, positions,
-                                cache.k[nd:], cache.v[nd:], pos)
-        ks.append(nk)
-        vs.append(nv)
-        new = Cache(k=jnp.concatenate(ks), v=jnp.concatenate(vs),
-                    pos=pos + 1)
+                                nk, nv, pos, first=nd)
+        new = Cache(k=nk, v=nv, pos=pos + 1)
     elif cfg.family == SSM:
         def block(h, sc):
             p, cx, cbc, st = sc
@@ -402,47 +397,38 @@ def decode_step(params, tokens, cache: Cache, cfg: ArchConfig, *,
 
 
 def _dense_decode(layers, x, cfg, positions, ck, cv, pos, flags):
-    # The stacked KV cache rides in the scan CARRY (per-layer
-    # dynamic_update_index) rather than as xs/ys: while-loop carries can
-    # be updated in place by XLA, so the multi-GB cache is not
-    # double-buffered (§Perf iteration 3).
+    return _attn_decode(layers["attn"], layers["mlp"],
+                        lambda p, h: mlp_block(p, h, cfg), x, cfg, positions,
+                        ck, cv, pos, flags)
+
+
+def _moe_decode(layers, x, cfg, positions, ck, cv, pos, first=0):
+    return _attn_decode(layers["attn"], layers["moe"],
+                        lambda p, h: moe_ffn(p, h, cfg, cfg.moe), x, cfg,
+                        positions, ck, cv, pos, first=first)
+
+
+def _attn_decode(attn, ffn_params, ffn, x, cfg, positions, ck, cv, pos,
+                 flags=None, first=0):
+    """Attention + ``ffn`` layers over the stacked (L, B, S, G, hd) pool,
+    the first at pool layer ``first``.  The pool rides in the scan CARRY
+    and each layer writes its new K/V row into it in place
+    (``attention_block(..., layer=li)``): no per-layer slice, copy or
+    write-back, and while-loop carries are updated in place by XLA, so the
+    multi-GB cache is not double-buffered (§Perf iteration 3)."""
     def block(carry, sc):
         h, ck, cv, li = carry
-        pa, pm, fl = sc
-        k_l = jax.lax.dynamic_index_in_dim(ck, li, 0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(cv, li, 0, keepdims=False)
-        a, (nk, nv) = attention_block(pa, h, cfg, layer_is_local=fl,
-                                      positions=positions,
-                                      kv_cache=(k_l, v_l), cache_pos=pos)
+        pa, pf, fl = sc
+        a, (ck, cv) = attention_block(pa, h, cfg, layer_is_local=fl,
+                                      positions=positions, kv_cache=(ck, cv),
+                                      cache_pos=pos, layer=li)
         h = h + a
-        h = h + mlp_block(pm, h, cfg)
-        ck = jax.lax.dynamic_update_index_in_dim(ck, nk, li, 0)
-        cv = jax.lax.dynamic_update_index_in_dim(cv, nv, li, 0)
+        h = h + ffn(pf, h)
         return (h, ck, cv, li + 1), None
 
     (x, nk, nv, _), _ = _scan(
-        block, (x, ck, cv, jnp.zeros((), jnp.int32)),
-        (layers["attn"], layers["mlp"], flags))
-    return x, nk, nv
-
-
-def _moe_decode(layers, x, cfg, positions, ck, cv, pos):
-    def block(carry, sc):
-        h, ck, cv, li = carry
-        pa, pm = sc
-        k_l = jax.lax.dynamic_index_in_dim(ck, li, 0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(cv, li, 0, keepdims=False)
-        a, (nk, nv) = attention_block(pa, h, cfg, positions=positions,
-                                      kv_cache=(k_l, v_l), cache_pos=pos)
-        h = h + a
-        h = h + moe_ffn(pm, h, cfg, cfg.moe)
-        ck = jax.lax.dynamic_update_index_in_dim(ck, nk, li, 0)
-        cv = jax.lax.dynamic_update_index_in_dim(cv, nv, li, 0)
-        return (h, ck, cv, li + 1), None
-
-    (x, nk, nv, _), _ = _scan(
-        block, (x, ck, cv, jnp.zeros((), jnp.int32)),
-        (layers["attn"], layers["moe"]))
+        block, (x, ck, cv, jnp.asarray(first, jnp.int32)),
+        (attn, ffn_params, flags))
     return x, nk, nv
 
 

@@ -1,5 +1,6 @@
-"""Compile the main-path Pallas kernels and the full-width Llama-3.2-3B
-decode step for a described (not attached) TPU v5e chip.
+"""Compile the main-path Pallas kernels, the full-width Llama-3.2-3B
+decode step and a Mistral-Nemo-12B-width decode step for a described (not
+attached) TPU v5e chip.
 
 Nothing runs: these tests catch what only the chip's compiler refuses —
 tiling rules, unlowerable primitives, VMEM and HBM limits — that the
@@ -8,6 +9,7 @@ a fixture (never at import): only one process may load the TPU library,
 and under pytest-xdist only the worker given this file does.
 """
 
+import dataclasses
 import functools
 import os
 
@@ -58,6 +60,10 @@ def _struct(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _place(sharding, tree):
+    return jax.tree.map(lambda a: _struct(sharding, a.shape, a.dtype), tree)
+
+
 @pytest.mark.parametrize("pinned_rows", [0, 1024])
 def test_flash_attention_compiles(one_chip, pinned_rows):
     """Llama-3.2-3B prefill widths: B=1, S=4096, H=24, G=8, D=128."""
@@ -94,14 +100,9 @@ def test_llama3p2_3b_decode_step_compiles(one_chip):
     max_seq 1024), from eval_shape structs; weights, cache and outputs
     fit the chip's 16 GiB HBM."""
     cfg = get_arch("llama3.2-3b")
-
-    def place(tree):
-        return jax.tree.map(
-            lambda a: _struct(one_chip, a.shape, a.dtype), tree)
-
-    params = place(jax.eval_shape(functools.partial(init_params, cfg),
-                                  jax.random.key(0)))
-    cache = place(jax.eval_shape(lambda: init_cache(cfg, 4, 1024)))
+    params = _place(one_chip, jax.eval_shape(
+        functools.partial(init_params, cfg), jax.random.key(0)))
+    cache = _place(one_chip, jax.eval_shape(lambda: init_cache(cfg, 4, 1024)))
     tokens = _struct(one_chip, (4, 1), jnp.int32)
     compiled = _compile(functools.partial(decode_step, cfg=cfg),
                         params, tokens, cache)
@@ -109,3 +110,19 @@ def test_llama3p2_3b_decode_step_compiles(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 7e9 < mem.argument_size_in_bytes < used < V5E_HBM_BYTES
+
+
+def test_dense_decode_writes_kv_in_place(one_chip):
+    """Mistral-Nemo-12B widths with 2 layers, batch 8 over max_seq 4096,
+    one position per row: each layer writes its new K/V row in place in
+    the stacked pool, so the step's temporaries hold no copy of a layer's
+    K or V (67 MB each)."""
+    cfg = dataclasses.replace(get_arch("mistral-nemo-12b"), n_layers=2)
+    params = _place(one_chip, jax.eval_shape(
+        functools.partial(init_params, cfg), jax.random.key(0)))
+    cache = _place(one_chip, jax.eval_shape(lambda: init_cache(cfg, 8, 4096)))
+    cache = cache._replace(pos=_struct(one_chip, (8,), jnp.int32))
+    tokens = _struct(one_chip, (8, 1), jnp.int32)
+    compiled = _compile(functools.partial(decode_step, cfg=cfg),
+                        params, tokens, cache)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**20
